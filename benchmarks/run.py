@@ -42,6 +42,8 @@ def main() -> None:
                          "collective counts) so the perf trajectory is "
                          "tracked across PRs")
     args = ap.parse_args()
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (bench_convex, bench_kernels, bench_roofline,
                             bench_serving, paper_tables)

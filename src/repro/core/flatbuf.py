@@ -360,6 +360,53 @@ def unflatten(layout: FlatLayout, buckets: Sequence, *, leading: int = 0):
     return jax.tree.unflatten(layout.treedef, vals)
 
 
+def _scatter_leaves(layout: FlatLayout, leaves: Sequence, bucket_avals,
+                    leading: int):
+    """Write each leaf into its slot of one zero bucket per bucket —
+    the transpose of :func:`unflatten`, as in-place
+    ``dynamic_update_slice`` writes: no per-leaf bucket-sized buffer."""
+    out = []
+    for b, aval in enumerate(bucket_avals):
+        lead = aval.shape[:leading]
+        S = layout.bucket_shard_count(b)
+        width = layout.bucket_local_rows(b) * LANE
+        flat = jnp.zeros(lead + ((S, width) if S > 1 else (width,)),
+                         aval.dtype)
+        for s in layout.bucket_slots(b):
+            x = leaves[s.index].astype(aval.dtype)
+            x = (_to_shard_major(x, s.shard_dims, leading) if S > 1
+                 else x.reshape(lead + (-1,)))
+            start = (0,) * (x.ndim - 1) + (s.row_offset * LANE,)
+            flat = jax.lax.dynamic_update_slice(flat, x, start)
+        out.append(flat.reshape(aval.shape))
+    return out
+
+
+def view(layout: FlatLayout, buckets: Sequence, *, leading: int = 0):
+    """The pytree view of ``buckets``, for differentiating through:
+    values as :func:`unflatten`, but the gradient writes each leaf's
+    cotangent into its slot of one zero bucket
+    (:func:`_scatter_leaves`).  AD through the plain slices would pad
+    every leaf's cotangent to a whole bucket and sum the pads — one
+    bucket-sized buffer per leaf.  Call it outside any ``vmap`` (pass
+    ``leading``): batching turns the slot writes into scatters."""
+    avals = [jax.ShapeDtypeStruct(b.shape, b.dtype) for b in buckets]
+
+    @jax.custom_vjp
+    def _view(bufs):
+        return unflatten(layout, list(bufs), leading=leading)
+
+    def _fwd(bufs):
+        return _view(bufs), None
+
+    def _bwd(_, ct):
+        return (tuple(_scatter_leaves(layout, jax.tree.leaves(ct), avals,
+                                      leading)),)
+
+    _view.defvjp(_fwd, _bwd)
+    return _view(tuple(buckets))
+
+
 # ---------------------------------------------------------------------------
 # Resident bucket state
 # ---------------------------------------------------------------------------

@@ -29,8 +29,9 @@ fields hold ``flatbuf.BucketState`` buffers instead of pytrees — for
 EVERY layout, including within-worker-sharded (FSDP/TP) ones, whose
 leaves ride (dtype, sharding-class) sub-buckets (``flatbuf.shard_classes``)
 kept row-sharded on the bus.  Local steps differentiate the loss THROUGH the
-bucket view — ``unflatten`` is part of the forward graph, so autodiff
-transposes it into grad buckets for free — and the fused optimizer
+bucket view — ``flatbuf.view`` is part of the forward graph, and its
+gradient writes each leaf's grad into its slot of one grad bucket — and
+the fused optimizer
 consumes/produces buckets directly: zero explicit flatten/unflatten
 between sync boundaries, vs 10 full-state pack/unpack HBM passes per
 step on the tree-in/tree-out kernel path.  Sync (mean / sign / EF-sign
@@ -269,9 +270,8 @@ def make_packed_mean(mesh, worker_axes: tuple[str, ...]):
             return comp.unpack_signs(allp, alls, local.shape[1:],
                                      axis=pack_axis).mean(axis=0)
 
-        from repro.utils import shard_map_compat
-        g = shard_map_compat(f, mesh=mesh, in_specs=P(axis), out_specs=P(),
-                             manual_axes=worker_axes)
+        g = jax.shard_map(f, mesh=mesh, in_specs=P(axis), out_specs=P(),
+                          axis_names=set(worker_axes), check_vma=False)
         return g(d)
 
     return packed_mean
@@ -324,15 +324,13 @@ def make_packed_mean_flat(mesh, worker_axes: tuple[str, ...]):
             alls = alls.reshape(W, -1)
             return comp.unpack_bucket_signs(allp, alls, seg_ids_j).mean(axis=0)
 
-        from repro.utils import shard_map_compat
-        # fully manual over ALL mesh axes (the only mode jax 0.4.x
-        # lowers without an XLA IsManualSubgroup abort): the in_specs
-        # place the worker dim and the class's row sharding; mesh axes
-        # outside worker+class replicate the (cheap, shard-local)
-        # pack/unpack work, the payload gather runs over the worker
-        # axes only, and the scale totals psum over the class axes only
-        g = shard_map_compat(f, mesh=mesh, in_specs=P(axis, cls_spec),
-                             out_specs=P(cls_spec), manual_axes=None)
+        # fully manual over ALL mesh axes: the in_specs place the
+        # worker dim and the class's row sharding; mesh axes outside
+        # worker+class replicate the (cheap, shard-local) pack/unpack
+        # work, the payload gather runs over the worker axes only, and
+        # the scale totals psum over the class axes only
+        g = jax.shard_map(f, mesh=mesh, in_specs=P(axis, cls_spec),
+                          out_specs=P(cls_spec), check_vma=False)
         return g(bucket)
 
     return packed_mean_flat
@@ -404,16 +402,14 @@ def make_packed_mean_coalesced(mesh, worker_axes: tuple[str, ...]):
                 so += ns
             return tuple(outs)
 
-        from repro.utils import shard_map_compat
         # fully manual over ALL mesh axes, as make_packed_mean_flat:
         # each class's row sharding rides its own in/out spec, the
         # payload gather runs over the worker axes only
-        g = shard_map_compat(f, mesh=mesh,
-                             in_specs=tuple(P(axis, _cls_spec(c))
-                                            for c in classes),
-                             out_specs=tuple(P(_cls_spec(c))
-                                             for c in classes),
-                             manual_axes=None)
+        g = jax.shard_map(f, mesh=mesh,
+                          in_specs=tuple(P(axis, _cls_spec(c))
+                                         for c in classes),
+                          out_specs=tuple(P(_cls_spec(c)) for c in classes),
+                          check_vma=False)
         return list(g(*bufs))
 
     return packed_mean_coalesced
@@ -872,8 +868,8 @@ def _make_resident_local_sgd(run: RunConfig, loss_fn: Callable, *,
     """(init, local_step, sync) with state held resident in bucket form.
 
     Local steps differentiate the loss THROUGH the bucket view:
-    ``unflatten`` is part of the forward graph and autodiff transposes
-    it into grad buckets, so the fused optimizer update
+    ``flatbuf.view`` is part of the forward graph and its gradient
+    writes the leaf grads into grad buckets, so the fused optimizer update
     (``apply_sgd_buckets`` / ``apply_lars_buckets``) performs zero
     explicit flatten/unflatten — the pack cost of the flat bus is paid
     once per sync round (O(1/H)) instead of once per step.  Sync
@@ -935,14 +931,20 @@ def _make_resident_local_sgd(run: RunConfig, loss_fn: Callable, *,
         layout = state.params.layout
         step_no = state.step
 
-        def step_w(pbs, ubs, bw, rw):
-            def loss_b(bufs):
-                # the pytree view materialized here is the model's
-                # activation input; its AD transpose builds grad buckets
-                return loss_fn(flatbuf.unflatten(layout, list(bufs)), bw)
+        def loss_all(bufs):
+            # the pytree view materialized here is the model's activation
+            # input; its AD transpose writes the leaf grads straight into
+            # grad buckets (flatbuf.view, taken outside the worker vmap).
+            # Workers are independent, so the gradient of the summed
+            # loss is each worker's own gradient.
+            tree = flatbuf.view(layout, bufs, leading=1)
+            loss, metrics = jax.vmap(loss_fn)(tree, batch)
+            return loss.sum(), (loss, metrics)
 
-            (loss, metrics), gbs = jax.value_and_grad(
-                loss_b, has_aux=True)(tuple(pbs))
+        (_, (loss, metrics)), gbs_all = jax.value_and_grad(
+            loss_all, has_aux=True)(tuple(state.params.buckets))
+
+        def update_w(pbs, gbs, ubs, rw):
             gbs = list(gbs)
             if opt.noise_eta > 0:
                 gbs = _bucket_noise(layout, gbs, rw, step=step_no,
@@ -962,17 +964,17 @@ def _make_resident_local_sgd(run: RunConfig, loss_fn: Callable, *,
                     kernel=comp_kernel)
             if telemetry:
                 p2, u2, (gsq, usq) = out
-                return tuple(p2), tuple(u2), loss, metrics, gsq, usq
+                return tuple(p2), tuple(u2), gsq, usq
             p2, u2 = out
-            return tuple(p2), tuple(u2), loss, metrics
+            return tuple(p2), tuple(u2)
 
-        out = jax.vmap(step_w)(
-            state.params.buckets, state.momentum.buckets, batch, rngs)
+        out = jax.vmap(update_w)(state.params.buckets, gbs_all,
+                                 state.momentum.buckets, rngs)
         if telemetry:
-            p, u, loss, metrics, gsq_w, usq_w = out
+            p, u, gsq_w, usq_w = out
             new_stats = tstats.accumulate_step(state.stats, gsq_w, usq_w)
         else:
-            p, u, loss, metrics = out
+            p, u = out
             new_stats = state.stats
         metrics = jax.tree.map(lambda x: x.mean(), metrics)
         metrics = {**metrics, "loss": loss.mean(), "lr": lr}

@@ -23,6 +23,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import ops as _ops
+
 NEG_INF = -1e30
 
 
@@ -73,7 +75,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *, scale: float, causal: bool,
                                              "block_q", "block_k", "interpret"))
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
                          scale: float = 0.0, block_q: int = 128,
-                         block_k: int = 128, interpret: bool = True):
+                         block_k: int = 128, interpret: bool | None = None):
     """q: (BH, Sq, D); k, v: (BH, Sk, D). Returns (BH, Sq, D)."""
     BH, Sq, D = q.shape
     Sk = k.shape[1]
@@ -96,5 +98,5 @@ def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
         ],
         out_specs=pl.BlockSpec((1, bq, D), lambda h, i: (h, i, 0)),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
+        interpret=_ops.interpret_mode(interpret),
     )(q, k, v)

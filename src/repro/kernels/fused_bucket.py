@@ -25,7 +25,9 @@ which adds two tiny per-grid-block partial sums to the SAME launch —
 sum(g^2) of the raw gradient and sum((lr*step)^2) of the applied update
 — so per-round grad-norm^2 / update-norm^2 telemetry costs zero extra
 HBM passes: the operands are already streaming through VMEM for the
-update; the stats are a few extra VPU ops plus a (num_blocks, 1) write.
+update; the stats are a few extra VPU ops plus one (8, 128) tile written
+per grid block (``_partials_spec`` — Mosaic accepts no smaller output
+block than one whole tile).
 
 Reduction kernels mask the final partial grid block explicitly: the
 grid over ``cdiv(rows, BLOCK_ROWS)`` reads out-of-bounds rows in its
@@ -50,8 +52,31 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import ops as _ops
+
 LANE = 128
+SUBLANE = 8
 BLOCK_ROWS = 256          # (256, 128) f32 tile = 128 KiB per operand
+
+
+def _partials_spec(n: int):
+    """Out spec + shape for one f32 partial sum per grid block.
+
+    The TPU lowering takes no output block smaller than one (8, 128)
+    tile unless it spans the whole array, so each grid step writes its
+    partial broadcast over a whole tile of an (n, 8, 128) array and
+    :func:`_sum_partials` reads the n values back at ``[:, 0, 0]``."""
+    return (pl.BlockSpec((1, SUBLANE, LANE), lambda i: (i, 0, 0)),
+            jax.ShapeDtypeStruct((n, SUBLANE, LANE), jnp.float32))
+
+
+def _write_partial(o_ref, value):
+    o_ref[...] = jnp.broadcast_to(value, o_ref.shape)
+
+
+def _sum_partials(out):
+    """Sum of the per-block partials, in block order."""
+    return out[:, 0, 0].sum()
 
 
 def _block_rows(rows: int, shards: int) -> int:
@@ -87,7 +112,7 @@ def _sgd_kernel(lr_ref, wd_ref, p_ref, g_ref, u_ref, po_ref, uo_ref,
         # which the reductions must mask (cf. _sq_sum_kernel)
         mask = _row_mask(g.shape, pl.program_id(0), br, rows)
         gm = jnp.where(mask, g, 0.0)
-        stat_refs[0][0, 0] = jnp.sum(gm * gm)
+        _write_partial(stat_refs[0], jnp.sum(gm * gm))
     if weight_decay:
         # wd_ref is the (br, 1) per-row mask: 1.0 on decayed leaves' rows
         g = g + (weight_decay * wd_ref[...]) * p
@@ -98,7 +123,7 @@ def _sgd_kernel(lr_ref, wd_ref, p_ref, g_ref, u_ref, po_ref, uo_ref,
     uo_ref[...] = u_new.astype(uo_ref.dtype)
     if stat_refs:
         dm = jnp.where(mask, d, 0.0)
-        stat_refs[1][0, 0] = jnp.sum(dm * dm)
+        _write_partial(stat_refs[1], jnp.sum(dm * dm))
 
 
 @functools.partial(jax.jit, static_argnames=("momentum", "weight_decay",
@@ -107,7 +132,7 @@ def _sgd_kernel(lr_ref, wd_ref, p_ref, g_ref, u_ref, po_ref, uo_ref,
 def fused_sgd_bucket_2d(p, g, u, lr, wd_row, *, momentum: float,
                         weight_decay: float, nesterov: bool,
                         stats: bool = False, shards: int = 1,
-                        interpret: bool = True):
+                        interpret: bool | None = None):
     """One fused SGD launch over a whole bucket.
 
     p, g, u: (rows, 128) same dtype; lr: (1, 1) f32 (SMEM, may be
@@ -120,12 +145,12 @@ def fused_sgd_bucket_2d(p, g, u, lr, wd_row, *, momentum: float,
     n = pl.cdiv(rows, br)
     spec = pl.BlockSpec((br, LANE), lambda i: (i, 0))
     mspec = pl.BlockSpec((br, 1), lambda i: (i, 0))
-    sspec = pl.BlockSpec((1, 1), lambda i: (i, 0))
+    sspec, sshape = _partials_spec(n)
     out_specs = [spec, spec] + ([sspec, sspec] if stats else [])
     out_shape = [jax.ShapeDtypeStruct(p.shape, p.dtype),
                  jax.ShapeDtypeStruct(u.shape, u.dtype)]
     if stats:
-        out_shape += [jax.ShapeDtypeStruct((n, 1), jnp.float32)] * 2
+        out_shape += [sshape, sshape]
     out = pl.pallas_call(
         functools.partial(_sgd_kernel, momentum=momentum,
                           weight_decay=weight_decay, nesterov=nesterov,
@@ -135,35 +160,36 @@ def fused_sgd_bucket_2d(p, g, u, lr, wd_row, *, momentum: float,
                   spec, spec, spec],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=_ops.interpret_mode(interpret),
     )(lr, wd_row, p, g, u)
     if stats:
         po, uo, gsq, usq = out
-        return po, uo, gsq.sum(), usq.sum()
+        return po, uo, _sum_partials(gsq), _sum_partials(usq)
     return out
 
 
 def _sq_sum_kernel(x_ref, o_ref, *, rows, br):
     x = x_ref[...].astype(jnp.float32)
     x = jnp.where(_row_mask(x.shape, pl.program_id(0), br, rows), x, 0.0)
-    o_ref[0, 0] = jnp.sum(x * x)
+    _write_partial(o_ref, jnp.sum(x * x))
 
 
 @functools.partial(jax.jit, static_argnames=("shards", "interpret"))
-def sq_sum_2d(x, *, shards: int = 1, interpret: bool = True):
+def sq_sum_2d(x, *, shards: int = 1, interpret: bool | None = None):
     """sum(x^2) over a bucket (f32 accumulate) — one HBM read."""
     rows = x.shape[0]
     br = _block_rows(rows, shards)
     n = pl.cdiv(rows, br)
+    ospec, oshape = _partials_spec(n)
     out = pl.pallas_call(
         functools.partial(_sq_sum_kernel, rows=rows, br=br),
         grid=(n,),
         in_specs=[pl.BlockSpec((br, LANE), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        interpret=interpret,
+        out_specs=ospec,
+        out_shape=oshape,
+        interpret=_ops.interpret_mode(interpret),
     )(x)
-    return out.sum()
+    return _sum_partials(out)
 
 
 def _row_abs_sum_kernel(x_ref, o_ref):
@@ -174,7 +200,7 @@ def _row_abs_sum_kernel(x_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("shards", "interpret"))
-def row_abs_sum_2d(x, *, shards: int = 1, interpret: bool = True):
+def row_abs_sum_2d(x, *, shards: int = 1, interpret: bool | None = None):
     """(rows, 1) f32 per-row |x| sums — one HBM read of the bucket."""
     rows = x.shape[0]
     br = _block_rows(rows, shards)
@@ -184,7 +210,7 @@ def row_abs_sum_2d(x, *, shards: int = 1, interpret: bool = True):
         in_specs=[pl.BlockSpec((br, LANE), lambda i: (i, 0))],
         out_specs=pl.BlockSpec((br, 1), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((rows, 1), jnp.float32),
-        interpret=interpret,
+        interpret=_ops.interpret_mode(interpret),
     )(x)
 
 
@@ -202,7 +228,7 @@ def _lars_row_norms_kernel(wd_ref, p_ref, g_ref, pn_ref, gn_ref, *,
 
 @functools.partial(jax.jit, static_argnames=("weight_decay", "shards", "interpret"))
 def lars_row_norms_2d(p, g, wd_row, *, weight_decay: float, shards: int = 1,
-                      interpret: bool = True):
+                      interpret: bool | None = None):
     """Per-row sum-of-squares of p and of g + wd*mask*p, one HBM pass.
 
     Returns (p_sq, g_sq), each (rows, 1) f32. The per-layer LARS norms
@@ -221,7 +247,7 @@ def lars_row_norms_2d(p, g, wd_row, *, weight_decay: float, shards: int = 1,
         out_specs=[mspec, mspec],
         out_shape=[jax.ShapeDtypeStruct((rows, 1), jnp.float32),
                    jax.ShapeDtypeStruct((rows, 1), jnp.float32)],
-        interpret=interpret,
+        interpret=_ops.interpret_mode(interpret),
     )(wd_row, p, g)
 
 
@@ -237,7 +263,7 @@ def _lars_kernel(lr_ref, wd_ref, r_ref, p_ref, g_ref, u_ref, po_ref, uo_ref,
         # mask the final partial grid block (cf. _sgd_kernel)
         mask = _row_mask(g.shape, pl.program_id(0), br, rows)
         gm = jnp.where(mask, g, 0.0)
-        stat_refs[0][0, 0] = jnp.sum(gm * gm)
+        _write_partial(stat_refs[0], jnp.sum(gm * gm))
     if weight_decay:
         g = g + (weight_decay * wd_ref[...]) * p
     # r_ref is the (br, 1) per-row trust ratio (1.0 on norm/bias rows)
@@ -249,7 +275,7 @@ def _lars_kernel(lr_ref, wd_ref, r_ref, p_ref, g_ref, u_ref, po_ref, uo_ref,
     uo_ref[...] = u_new.astype(uo_ref.dtype)
     if stat_refs:
         dm = jnp.where(mask, d, 0.0)
-        stat_refs[1][0, 0] = jnp.sum(dm * dm)
+        _write_partial(stat_refs[1], jnp.sum(dm * dm))
 
 
 @functools.partial(jax.jit, static_argnames=("momentum", "weight_decay",
@@ -258,7 +284,7 @@ def _lars_kernel(lr_ref, wd_ref, r_ref, p_ref, g_ref, u_ref, po_ref, uo_ref,
 def fused_lars_bucket_2d(p, g, u, lr, wd_row, ratio_row, *, momentum: float,
                          weight_decay: float, nesterov: bool,
                          stats: bool = False, shards: int = 1,
-                         interpret: bool = True):
+                         interpret: bool | None = None):
     """One fused LARS launch over a whole bucket.
 
     p, g, u: (rows, 128) same dtype; lr: (1, 1) f32; wd_row: (rows, 1)
@@ -272,12 +298,12 @@ def fused_lars_bucket_2d(p, g, u, lr, wd_row, ratio_row, *, momentum: float,
     n = pl.cdiv(rows, br)
     spec = pl.BlockSpec((br, LANE), lambda i: (i, 0))
     mspec = pl.BlockSpec((br, 1), lambda i: (i, 0))
-    sspec = pl.BlockSpec((1, 1), lambda i: (i, 0))
+    sspec, sshape = _partials_spec(n)
     out_specs = [spec, spec] + ([sspec, sspec] if stats else [])
     out_shape = [jax.ShapeDtypeStruct(p.shape, p.dtype),
                  jax.ShapeDtypeStruct(u.shape, u.dtype)]
     if stats:
-        out_shape += [jax.ShapeDtypeStruct((n, 1), jnp.float32)] * 2
+        out_shape += [sshape, sshape]
     out = pl.pallas_call(
         functools.partial(_lars_kernel, momentum=momentum,
                           weight_decay=weight_decay, nesterov=nesterov,
@@ -287,11 +313,11 @@ def fused_lars_bucket_2d(p, g, u, lr, wd_row, ratio_row, *, momentum: float,
                   spec, spec, spec],
         out_specs=out_specs,
         out_shape=out_shape,
-        interpret=interpret,
+        interpret=_ops.interpret_mode(interpret),
     )(lr, wd_row, ratio_row, p, g, u)
     if stats:
         po, uo, gsq, usq = out
-        return po, uo, gsq.sum(), usq.sum()
+        return po, uo, _sum_partials(gsq), _sum_partials(usq)
     return out
 
 
@@ -301,7 +327,7 @@ def _scale_sign_rows_kernel(x_ref, s_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("shards", "interpret"))
-def scale_sign_rows_2d(x, scale_row, *, shards: int = 1, interpret: bool = True):
+def scale_sign_rows_2d(x, scale_row, *, shards: int = 1, interpret: bool | None = None):
     """y = sign(x) * scale_row (per-row scales; second compressor pass)."""
     rows = x.shape[0]
     br = _block_rows(rows, shards)
@@ -313,5 +339,5 @@ def scale_sign_rows_2d(x, scale_row, *, shards: int = 1, interpret: bool = True)
         in_specs=[spec, mspec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
-        interpret=interpret,
+        interpret=_ops.interpret_mode(interpret),
     )(x, scale_row)

@@ -25,6 +25,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import ops as _ops
+
 LANE = 128
 BLOCK_ROWS = 256          # (256, 128) f32 tile = 128 KiB per operand
 
@@ -46,7 +48,7 @@ def _kernel(lr_ref, p_ref, g_ref, u_ref, po_ref, uo_ref, *, momentum: float,
 @functools.partial(jax.jit, static_argnames=("momentum", "weight_decay",
                                              "nesterov", "interpret"))
 def fused_sgd_2d(p, g, u, lr, *, momentum: float, weight_decay: float,
-                 nesterov: bool, interpret: bool = True):
+                 nesterov: bool, interpret: bool | None = None):
     """p, g, u: (rows, 128) same dtype; lr: (1,1) f32. Returns (p', u')."""
     rows = p.shape[0]
     br = min(BLOCK_ROWS, rows)
@@ -60,5 +62,5 @@ def fused_sgd_2d(p, g, u, lr, *, momentum: float, weight_decay: float,
         out_specs=[spec, spec],
         out_shape=[jax.ShapeDtypeStruct(p.shape, p.dtype),
                    jax.ShapeDtypeStruct(u.shape, u.dtype)],
-        interpret=interpret,
+        interpret=_ops.interpret_mode(interpret),
     )(lr, p, g, u)

@@ -2,8 +2,12 @@
 
 Handle arbitrary tensor shapes by flattening to the (rows, 128) lane
 layout (zero-padding the tail), dispatching the kernel, and restoring the
-original shape. ``interpret`` defaults to True off-TPU so the kernels are
-validated on CPU; on TPU the compiled path is used.
+original shape.
+
+:func:`interpret_mode` is the one place that decides whether a Pallas
+kernel runs compiled or in the interpreter: every kernel's
+``interpret=None`` default resolves through it (compiled on TPU,
+interpreted elsewhere so the kernels are validated on CPU).
 """
 from __future__ import annotations
 
@@ -19,6 +23,11 @@ LANE = 128
 
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
+
+
+def interpret_mode(interpret: bool | None) -> bool:
+    """``interpret`` as given, or — for ``None`` — True off-TPU."""
+    return (not _on_tpu()) if interpret is None else bool(interpret)
 
 
 def _to_2d(x):
@@ -39,8 +48,6 @@ def _from_2d(y, pad, shape):
 def fused_sgd(p, g, u, *, lr, momentum: float, weight_decay: float = 0.0,
               nesterov: bool = True, interpret: bool | None = None):
     """Fused SGD update; returns (p_new, u_new). lr may be traced."""
-    if interpret is None:
-        interpret = not _on_tpu()
     p2, pad = _to_2d(p)
     g2, _ = _to_2d(g)
     u2, _ = _to_2d(u)
@@ -58,8 +65,6 @@ def sign_compress(x, *, interpret: bool | None = None):
     lane-padded buffer size, so tensors whose size is not a multiple of
     128 get an unbiased L1 scale (regression-tested at size 130).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
     x2, pad = _to_2d(x)
     total = _sc.abs_sum_2d(x2, interpret=interpret)
     scale = (total / x.size).reshape(1, 1)
@@ -81,8 +86,6 @@ def bucket_fused_sgd(p2, g2, u2, wd_row, *, lr, momentum: float,
     ``flatbuf.wd_rows``. Returns (p2', u2'), or with ``stats=True``
     (p2', u2', sum(g^2), sum(||update||^2)) from the SAME launch
     (telemetry; zero extra HBM passes)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     lr2 = jnp.asarray(lr, jnp.float32).reshape(1, 1)
     return _fb.fused_sgd_bucket_2d(p2, g2, u2, lr2, jnp.asarray(wd_row),
                                    momentum=momentum,
@@ -93,8 +96,6 @@ def bucket_fused_sgd(p2, g2, u2, wd_row, *, lr, momentum: float,
 
 def bucket_sq_sum(x2, *, shards: int = 1, interpret: bool | None = None):
     """sum(x^2) over a bucket (f32) — one fused HBM pass."""
-    if interpret is None:
-        interpret = not _on_tpu()
     return _fb.sq_sum_2d(x2, shards=shards, interpret=interpret)
 
 
@@ -105,8 +106,6 @@ def bucket_lars_norms(p2, g2, wd_row, *, weight_decay: float,
     Returns ((rows, 1) f32, (rows, 1) f32); the per-layer LARS norms
     finish as one segmented reduction (see ``flatbuf.row_segments``).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
     return _fb.lars_row_norms_2d(p2, g2, jnp.asarray(wd_row),
                                  weight_decay=weight_decay, shards=shards,
                                  interpret=interpret)
@@ -122,8 +121,6 @@ def bucket_fused_lars(p2, g2, u2, wd_row, ratio_row, *, lr, momentum: float,
     norm/bias rows, which take the plain LR). Returns (p2', u2'), or
     with ``stats=True`` (p2', u2', sum(g^2), sum(||update||^2)) from
     the SAME launch (telemetry; zero extra HBM passes)."""
-    if interpret is None:
-        interpret = not _on_tpu()
     lr2 = jnp.asarray(lr, jnp.float32).reshape(1, 1)
     return _fb.fused_lars_bucket_2d(p2, g2, u2, lr2, jnp.asarray(wd_row),
                                     ratio_row, momentum=momentum,
@@ -144,8 +141,6 @@ def bucket_sign_compress(x2, seg_ids, seg_sizes, *, shards: int = 1,
 
     Returns (y2 f32, scales (num_segments,) f32).
     """
-    if interpret is None:
-        interpret = not _on_tpu()
     seg_ids = jnp.asarray(seg_ids)
     row_sums = _fb.row_abs_sum_2d(x2, shards=shards, interpret=interpret)
     totals = jax.ops.segment_sum(row_sums[:, 0], seg_ids,
@@ -161,8 +156,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     interpret: bool | None = None):
     """GQA flash attention. q: (B, S, H, D); k, v: (B, S, KH, D)."""
     from repro.kernels.flash_attention import flash_attention_bhsd
-    if interpret is None:
-        interpret = not _on_tpu()
     B, Sq, H, D = q.shape
     KH = k.shape[2]
     G = H // KH

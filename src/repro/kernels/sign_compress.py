@@ -16,34 +16,37 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import ops as _ops
+from repro.kernels import fused_bucket as _fb
+
 LANE = 128
 BLOCK_ROWS = 256
 
 
 def _abs_sum_kernel(x_ref, o_ref, *, rows, br):
-    from repro.kernels.fused_bucket import _row_mask
     x = x_ref[...].astype(jnp.float32)
     # mask the final partial grid block: its out-of-bounds rows are
     # undefined (NaN in interpret mode) and an unmasked reduction folds
     # them in whenever rows is not a multiple of BLOCK_ROWS
-    mask = _row_mask(x.shape, pl.program_id(0), br, rows)
-    o_ref[0, 0] = jnp.sum(jnp.where(mask, jnp.abs(x), 0.0))
+    mask = _fb._row_mask(x.shape, pl.program_id(0), br, rows)
+    _fb._write_partial(o_ref, jnp.sum(jnp.where(mask, jnp.abs(x), 0.0)))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def abs_sum_2d(x, *, interpret: bool = True):
+def abs_sum_2d(x, *, interpret: bool | None = None):
     rows = x.shape[0]
     br = min(BLOCK_ROWS, rows)
     n = pl.cdiv(rows, br)
+    ospec, oshape = _fb._partials_spec(n)
     out = pl.pallas_call(
         functools.partial(_abs_sum_kernel, rows=rows, br=br),
         grid=(n,),
         in_specs=[pl.BlockSpec((br, LANE), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        interpret=interpret,
+        out_specs=ospec,
+        out_shape=oshape,
+        interpret=_ops.interpret_mode(interpret),
     )(x)
-    return out.sum()
+    return _fb._sum_partials(out)
 
 
 def _scale_sign_kernel(s_ref, x_ref, o_ref):
@@ -53,7 +56,7 @@ def _scale_sign_kernel(s_ref, x_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def scale_sign_2d(x, scale, *, interpret: bool = True):
+def scale_sign_2d(x, scale, *, interpret: bool | None = None):
     rows = x.shape[0]
     br = min(BLOCK_ROWS, rows)
     spec = pl.BlockSpec((br, LANE), lambda i: (i, 0))
@@ -63,5 +66,5 @@ def scale_sign_2d(x, scale, *, interpret: bool = True):
         in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)), spec],
         out_specs=spec,
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
-        interpret=interpret,
+        interpret=_ops.interpret_mode(interpret),
     )(scale, x)

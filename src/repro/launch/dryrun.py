@@ -46,8 +46,7 @@ def _mem_fields(compiled):
 
 
 def _cost_fields(compiled):
-    from repro.utils import cost_analysis_dict
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes_accessed": float(ca.get("bytes accessed", 0.0))}
 

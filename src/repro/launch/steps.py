@@ -45,7 +45,8 @@ class TrainBundle:
     batch_shardings: Any = None
     telemetry: bool = False     # state carries a StatsAccumulator
     n_comp: int = 1             # compression-error slots (sub-buckets)
-    sync_lower: Any = None      # mesh only: lower sync for HLO ledger costs
+    step_lower: Any = None      # jit only: lower local_step (compiled text)
+    sync_lower: Any = None      # jit only: lower sync (HLO ledger costs)
     sync_plan: Any = None       # compiled syncplan.SyncPlan (fit's default)
     worker_set: Any = None      # backend.base.WorkerSet this bundle was built for
 
@@ -171,13 +172,10 @@ def build_train(run: RunConfig, *, mesh: Mesh | None = None,
         shard_cls = flatbuf.shard_classes(specs, lay_m)
         bucketable = flatbuf.replicated_tree(shard_cls)
         if run.local_sgd.wire_pack and run.local_sgd.sync_compression != "none":
-            from repro.utils import partial_auto_shard_map_supported
-            if partial_auto_shard_map_supported():
-                # per-leaf path for within-worker-sharded leaves on the
-                # NON-resident tree path; on jax 0.4.x it stays None ->
-                # plain GSPMD-hint pack/unpack
-                pm = (make_packed_mean(mesh, layout.worker_axes),
-                      pack_axes_tree(specs, lay_m))
+            # per-leaf path for within-worker-sharded leaves on the
+            # NON-resident tree path
+            pm = (make_packed_mean(mesh, layout.worker_axes),
+                  pack_axes_tree(specs, lay_m))
             pm_flat = make_packed_mean_flat(mesh, layout.worker_axes)
             if run.local_sgd.sync_coalesce:
                 # one payload gather per dtype, not per sharding class
@@ -229,24 +227,33 @@ def build_train(run: RunConfig, *, mesh: Mesh | None = None,
     # per-call shim in core/local_sgd.
     bundle.sync_plan = splan.make_sync_plan(bundle)
 
-    if mesh is not None and jit:
-        sspec = state_partition_specs(specs, layout, run, resident=resident,
-                                      telemetry=telemetry, bucket_layout=blay,
-                                      worker_set=worker_set)
-        bspec = inp.train_batch_pspecs(cfg, run.shape, layout)
-        ssh = _named(mesh, sspec)
-        bsh = _named(mesh, bspec)
-        bundle.state_shardings = ssh
-        bundle.batch_shardings = bsh
+    if jit:
+        step_kw: dict = {}
+        sync_kw: dict = {}
+        if mesh is not None:
+            sspec = state_partition_specs(specs, layout, run,
+                                          resident=resident,
+                                          telemetry=telemetry,
+                                          bucket_layout=blay,
+                                          worker_set=worker_set)
+            bspec = inp.train_batch_pspecs(cfg, run.shape, layout)
+            ssh = _named(mesh, sspec)
+            bsh = _named(mesh, bspec)
+            bundle.state_shardings = ssh
+            bundle.batch_shardings = bsh
+            step_kw = dict(in_shardings=(ssh, bsh, None),
+                           out_shardings=(ssh, None))
+            sync_kw = dict(in_shardings=(ssh,), out_shardings=ssh)
         # positional adapter for the optional lr_scale arg (pjit with
         # in_shardings rejects kwargs): passing None keeps the original
         # two-arg program; a scalar traces once and serves every value
         jstep = jax.jit(
             lambda s, b, lr_scale: local_step(s, b, lr_scale=lr_scale),
-            in_shardings=(ssh, bsh, None), out_shardings=(ssh, None))
+            **step_kw)
         bundle.local_step = (lambda s, b, lr_scale=None:
                              jstep(s, b, lr_scale))
-        # pjit rejects kwargs once in_shardings is given (jax 0.4.x), so
+        bundle.step_lower = (lambda s, b, lr_scale=None:
+                             jstep.lower(s, b, lr_scale))
         # jit a positional adapter for the static (group, compression,
         # plan, scope) args — SyncPlan is frozen/hashable, so each
         # distinct plan compiles once — and keep the kwarg interface
@@ -256,8 +263,7 @@ def build_train(run: RunConfig, *, mesh: Mesh | None = None,
             lambda s, group, compression, plan, scope: sync(
                 s, group=group, compression=compression, plan=plan,
                 scope=scope),
-            static_argnums=(1, 2, 3, 4), in_shardings=(ssh,),
-            out_shardings=ssh)
+            static_argnums=(1, 2, 3, 4), **sync_kw)
         bundle.sync = (lambda s, *, group=None, compression=None, plan=None,
                        scope=None: jsync(s, group, compression, plan, scope))
         bundle.sync_lower = (lambda s, *, group=None, compression=None,

@@ -106,6 +106,10 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
         bundle = backend.build(run)
     elif hasattr(backend, "adopt"):
         backend.adopt(bundle)
+    if mesh is None and bundle.layout is not None:
+        # a bundle built on a mesh carries it in its bound layout: the
+        # ledger then prices every round from the compiled sync's HLO
+        mesh = bundle.layout.mesh
     num_steps = num_steps or run.steps
     ls = run.local_sgd
 
@@ -166,10 +170,9 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
                     # jitted sync would drop jit's auto-resharding of
                     # host-resident init arrays, so the dispatch path
                     # keeps its own compile
-                    with mesh:
-                        txt = (bundle.sync_lower(state_avals, plan=p,
-                                                 scope=scope)
-                               .compile().as_text())
+                    txt = (bundle.sync_lower(state_avals, plan=p,
+                                             scope=scope)
+                           .compile().as_text())
                     cost = tele.hlo_sync_cost(txt)
                 except Exception as e:       # lowering quirks: keep analytic
                     log(f"ledger: hlo sync cost unavailable ({e!r}); "
@@ -510,6 +513,8 @@ def main():
                          "wall-clock per span at the cost of dispatch "
                          "pipelining (defaults off)")
     args = ap.parse_args()
+    from repro.utils import enable_compile_cache
+    enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke or args.arch != "paper-lm" \
         else configs.get("paper-lm")

@@ -27,7 +27,8 @@ def constrain(x, lay: MeshLayout | None, *axes: str | None):
     """
     if lay is None:
         return x
-    return jax.lax.with_sharding_constraint(x, lay.spec(*axes, dims=tuple(x.shape)))
+    return jax.lax.with_sharding_constraint(
+        x, lay.sharding(*axes, dims=tuple(x.shape)))
 
 
 # ---------------------------------------------------------------------------

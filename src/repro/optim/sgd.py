@@ -102,8 +102,8 @@ def apply_sgd_buckets(layout, pb, gb, ub, *, lr, momentum_coef: float,
     from repro.kernels import ops as kops
 
     if grad_clip:
-        # grad buckets have exact-zero padding (AD through the bucket
-        # view transposes slices into zero-pads), so the bucket norm
+        # grad buckets have exact-zero padding (the bucket view's
+        # gradient writes leaves into a zero bucket), so the bucket norm
         # equals the per-leaf global norm
         if kernel:
             gn2 = sum(kops.bucket_sq_sum(g, shards=layout.bucket_shard_count(b))

@@ -157,8 +157,11 @@ def jaxpr_op_counts(jaxpr, *, opaque=("pallas_call",)) -> dict:
 
 def parse_collectives(hlo_text: str, *, pod_size: int = 0) -> CollectiveSummary:
     summary = CollectiveSummary()
+    # a tuple result may hold parenthesized TPU tilings, e.g.
+    # (f32[]{:T(128)}, f32[]{:T(128)}): allow one nesting level
     pat = re.compile(
-        r"=\s*(\([^)]*\)|\S+)\s+(" + "|".join(COLLECTIVES) + r")(-start)?\(")
+        r"=\s*(\((?:[^()]|\([^()]*\))*\)|\S+)\s+("
+        + "|".join(COLLECTIVES) + r")(-start)?\(")
     for line in hlo_text.splitlines():
         m = pat.search(line)
         if not m:

@@ -64,8 +64,7 @@ def _measure(cfg, shape, mesh, lay, W):
     s = parse_collectives(compiled.as_text(),
                           pod_size=(mesh.devices.size // mesh.shape["pod"]
                                     if "pod" in mesh.axis_names else 0))
-    from repro.utils import cost_analysis_dict
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     return {"coll_bytes": s.total_bytes(), "coll_by_op": s.by_op(),
             "flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0))}

@@ -45,10 +45,8 @@ def measure_sync(arch: str, *, compression: str, wire_pack: bool,
     from repro.core import flatbuf
     from repro.core.local_sgd import (make_packed_mean, make_packed_mean_flat,
                                       pack_axes_tree)
-    from repro.utils import partial_auto_shard_map_supported
     pm = ((make_packed_mean(mesh, lay.worker_axes),
-           pack_axes_tree(specs, lay_m))
-          if wire_pack and partial_auto_shard_map_supported() else None)
+           pack_axes_tree(specs, lay_m)) if wire_pack else None)
     pm_flat = (make_packed_mean_flat(mesh, lay.worker_axes)
                if wire_pack and bucket_sync else None)
     cls = flatbuf.shard_classes(specs, lay_m)

@@ -49,6 +49,9 @@ class MeshLayout:
     # mesh axis sizes; when set, spec() drops rules that do not divide the
     # concrete dim (e.g. kv_heads=1 cannot shard over a 16-way model axis)
     sizes: dict[str, int] = field(default_factory=dict)
+    # the mesh this layout was bound to by with_mesh(): sharding
+    # constraints name it explicitly, so no mesh context is needed
+    mesh: Mesh | None = field(default=None, compare=False, repr=False)
 
     def rule(self, name: str) -> AxisVal:
         return self.rules.get(name)
@@ -107,7 +110,33 @@ class MeshLayout:
         return P(*parts)
 
     def with_mesh(self, mesh: Mesh) -> "MeshLayout":
-        return replace(self, sizes={a: int(mesh.shape[a]) for a in mesh.axis_names})
+        """Bind to ``mesh``: record its axis sizes, and drop rule axes the
+        mesh does not have (a ``("data",)`` mesh has no tensor-parallel
+        axis, so "model" rules become replicated).  Worker axes the
+        mesh lacks are an error: they define the workers."""
+        names = tuple(mesh.axis_names)
+        missing = [a for a in self.worker_axes if a not in names]
+        if missing:
+            raise ValueError(f"worker axes {missing} of the layout are not "
+                             f"axes of the mesh {names}")
+
+        def keep(v: AxisVal) -> AxisVal:
+            axes = () if v is None else (v,) if isinstance(v, str) else v
+            kept = tuple(a for a in axes if a in names)
+            if kept == tuple(axes):
+                return v
+            return kept[0] if len(kept) == 1 else (kept or None)
+
+        return replace(self, rules={k: keep(v) for k, v in self.rules.items()},
+                       sizes={a: int(mesh.shape[a]) for a in names},
+                       mesh=mesh)
+
+    def sharding(self, *axes: str | None, dims: tuple[int, ...] | None = None):
+        """The sharding constraint for logical ``axes``: a NamedSharding on
+        the bound mesh, or the bare PartitionSpec (resolved against the
+        context mesh) for a layout that was never bound."""
+        spec = self.spec(*axes, dims=dims)
+        return spec if self.mesh is None else NamedSharding(self.mesh, spec)
 
     def num_workers(self, mesh: Mesh) -> int:
         return int(np.prod([mesh.shape[a] for a in self.worker_axes])) if self.worker_axes else 1

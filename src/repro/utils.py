@@ -1,54 +1,30 @@
 """Small shared utilities."""
 from __future__ import annotations
 
+import os
+from pathlib import Path
+
 import jax
 
-
-def partial_auto_shard_map_supported() -> bool:
-    """True when shard_map can leave some mesh axes GSPMD-managed.
-
-    jax 0.4.x lowers partial-auto shard_map into an XLA
-    ``IsManualSubgroup`` check failure (hard abort), so callers that
-    would pin a collective over only the worker axes of a leaf that is
-    ALSO sharded within the worker must fall back to plain GSPMD
-    sharding hints there (correct, but the gather may move
-    uncompressed bytes; roofline/sync_probe quantifies the cost)."""
-    return hasattr(jax, "shard_map")
+# the checkout root: src/repro/utils.py -> ../..
+REPO_ROOT = Path(__file__).resolve().parents[2]
 
 
-def shard_map_compat(f, *, mesh, in_specs, out_specs,
-                     manual_axes: tuple[str, ...] | None = None):
-    """shard_map across jax versions.
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
 
-    Newer jax exposes ``jax.shard_map(..., check_vma=, axis_names=)``;
-    0.4.x has ``jax.experimental.shard_map.shard_map(..., check_rep=,
-    auto=)`` where ``auto`` is the complement of the manual axes.
-
-    ``manual_axes``: mesh axes the collective is pinned over; the rest
-    stay GSPMD-managed (partial-auto). ``None`` => fully manual over
-    ALL mesh axes — required when the operands are replicated within a
-    worker anyway (flat-bus buckets), and the only mode that lowers on
-    jax 0.4.x, whose partial-auto partitioning hits an XLA
-    ``IsManualSubgroup`` check failure.
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    nothing is set here.  Otherwise the cache lives at the fixed path
+    ``<checkout>/.jax_cache`` (listed in .gitignore): the path is part of
+    the cache key, so it must not move between runs.  Call it at the
+    start of an entry point, never at import time.
     """
-    manual = tuple(mesh.axis_names) if manual_axes is None else manual_axes
-    if partial_auto_shard_map_supported():
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False,
-                             axis_names=set(manual))
-    from jax.experimental.shard_map import shard_map as _sm
-    auto = frozenset(a for a in mesh.axis_names if a not in manual)
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False, auto=auto)
-
-
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions: 0.4.x returns a
-    per-device LIST of dicts, newer versions a single dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca or {}
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return env
+    path = str(REPO_ROOT / ".jax_cache")
+    jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def tree_map_pairs(fn, tree, *rest):

@@ -40,6 +40,19 @@ def test_parse_collectives_iota_groups():
     assert op.crosses_pod
 
 
+def test_parse_collectives_tpu_tuple_layouts():
+    """A tuple result whose TPU tilings carry parentheses (the two scalar
+    metrics of a local step, as the TPU compiler prints them)."""
+    hlo = ("%all-reduce.2 = (f32[]{:T(128)}, f32[]{:T(128)}) "
+           "all-reduce(%div.265, %convert.55), channel_id=1, "
+           "replica_groups=[1,4]<=[4], use_global_device_ids=true\n"
+           "%ag = u8[4,3096,16]{1,2,0:T(8,128)(4,1)S(1)} all-gather(%b), "
+           "replica_groups={{0,1,2,3}}, dimensions={0}")
+    ops = parse_collectives(hlo).ops
+    assert [(o.op, o.result_bytes, o.group_size) for o in ops] == [
+        ("all-reduce", 8, 4), ("all-gather", 4 * 3096 * 16, 4)]
+
+
 def test_analytic_flops_vs_cost_analysis():
     """Tiny dense config, fully unrolled + single-block attention/loss so
     cost_analysis sees everything; analytic model within 2x (the unrolled
@@ -57,8 +70,7 @@ def test_analytic_flops_vs_cost_analysis():
         return s / jnp.maximum(n, 1)
 
     compiled = jax.jit(fwd).lower(params, toks).compile()
-    from repro.utils import cost_analysis_dict
-    measured = cost_analysis_dict(compiled)["flops"]
+    measured = compiled.cost_analysis()["flops"]
     analytic = forward_flops(cfg, B, S)
     ratio = measured / analytic
     assert 0.5 < ratio < 2.0, (measured, analytic)

@@ -124,6 +124,25 @@ def test_shard_classes_follow_effective_spec():
     assert rep == {"ffn": False, "norm": True, "odd": True}
 
 
+def test_with_mesh_binds_mesh_and_drops_absent_axes():
+    """A layout bound to a mesh without a tensor-parallel axis replicates
+    its 'model' rules (instead of naming an axis the mesh lacks), carries
+    the mesh for its sharding constraints, and refuses a mesh missing a
+    worker axis with a message naming it."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.sharding.layout import train_layout
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    lay = train_layout(("data", "model"), worker_axes=("data",)).with_mesh(mesh)
+    assert lay.mesh is mesh and lay.sizes == {"data": 1}
+    assert lay.rule("heads") is None and lay.rule("mlp") is None
+    assert lay.spec("embed", "mlp") == P(None, None)
+    assert lay.sharding("embed", "mlp") == NamedSharding(mesh, P(None, None))
+    assert MeshLayout(("data",), ("data",)).sharding("embed") == P(None)
+    with pytest.raises(ValueError, match="worker axes \\['pod'\\]"):
+        train_layout(("pod", "data"), worker_axes=("pod",)).with_mesh(mesh)
+
+
 def test_shard_classes_uneven_tp_dim_matches_placement():
     """Divisibility-leak regression (ISSUE 4): a leaf whose TP dim does
     not divide the mesh axis must land in the class its PartitionSpec
