@@ -619,7 +619,10 @@ def make_local_sgd(run: RunConfig, loss_fn: Callable, *, num_workers: int,
         )
 
     def _worker_step(p, u, batch, rng, lr, step):
-        (loss, metrics), g = jax.value_and_grad(loss_fn, has_aux=True)(p, batch)
+        # named scopes label the step's ops in a device profile
+        with jax.named_scope("fwd_bwd"):
+            (loss, metrics), g = jax.value_and_grad(loss_fn,
+                                                    has_aux=True)(p, batch)
         if opt.noise_eta > 0:
             g = noise_mod.isotropic_noise(g, rng, step=step, eta=opt.noise_eta,
                                           gamma=opt.noise_gamma)
@@ -636,17 +639,20 @@ def make_local_sgd(run: RunConfig, loss_fn: Callable, *, num_workers: int,
             else:
                 gsq = gn2
         p0 = p
-        if opt.optimizer == "lars":
-            p, u = apply_lars(p, g, u, lr=lr, trust=opt.lars_trust,
-                              momentum_coef=ls.local_momentum,
-                              weight_decay=opt.weight_decay,
-                              nesterov=ls.nesterov, wd_mask=wd_mask,
-                              use_kernel=use_kernel)
-        else:
-            p, u = apply_sgd(p, g, u, lr=lr, momentum_coef=ls.local_momentum,
-                             weight_decay=opt.weight_decay, nesterov=ls.nesterov,
-                             wd_mask=wd_mask, grad_clip=opt.grad_clip,
-                             use_kernel=use_kernel)
+        with jax.named_scope("optimizer"):
+            if opt.optimizer == "lars":
+                p, u = apply_lars(p, g, u, lr=lr, trust=opt.lars_trust,
+                                  momentum_coef=ls.local_momentum,
+                                  weight_decay=opt.weight_decay,
+                                  nesterov=ls.nesterov, wd_mask=wd_mask,
+                                  use_kernel=use_kernel)
+            else:
+                p, u = apply_sgd(p, g, u, lr=lr,
+                                 momentum_coef=ls.local_momentum,
+                                 weight_decay=opt.weight_decay,
+                                 nesterov=ls.nesterov, wd_mask=wd_mask,
+                                 grad_clip=opt.grad_clip,
+                                 use_kernel=use_kernel)
         if telemetry:
             usq = sum(_sumsq(a.astype(jnp.float32) - b.astype(jnp.float32))
                       for a, b in zip(jax.tree.leaves(p), jax.tree.leaves(p0)))
@@ -717,10 +723,12 @@ def make_local_sgd(run: RunConfig, loss_fn: Callable, *, num_workers: int,
                     "compression override needs an anchor: configure "
                     "sync_compression/global_momentum so the state "
                     "allocates one (needs_anchor)")
-            if bucket_sync:
-                p = bucket_group_mean(state.params, g, bucketable)
-            else:
-                p = jax.tree.map(lambda x: group_mean(x, g), state.params)
+            with jax.named_scope("sync_mean"):
+                if bucket_sync:
+                    p = bucket_group_mean(state.params, g, bucketable)
+                else:
+                    p = jax.tree.map(lambda x: group_mean(x, g),
+                                     state.params)
             new_stats = state.stats
             if record:
                 # pre-/post-mean pair of the synced quantity, CENTERED
@@ -775,29 +783,31 @@ def make_local_sgd(run: RunConfig, loss_fn: Callable, *, num_workers: int,
                       for d, c in zip(jax.tree.leaves(delta),
                                       jax.tree.leaves(cs)))
             ref = _tree_sumsq_w(delta).sum()
-        if mode != "none" and ls.wire_pack:
-            # 1-bit wire format. Bucketized: one packed gather per dtype
-            # bucket (make_packed_mean_flat; meshless fallback in CPU
-            # tests). Per-leaf path kept for sharded leaves / equivalence.
-            pm, axes_tree = packed_mean_fn or (None, None)
-            if bucket_sync:
-                dbar = bucket_packed_mean(delta, bucketable,
-                                          flat_fn=packed_mean_flat_fn,
-                                          leaf_fn=pm, axes_tree=axes_tree)
-            else:
-                if pm is None:
-                    def pm(d, axis=-1):
-                        packed, scale = comp.pack_signs(d, axis=axis)
-                        return comp.unpack_signs(packed, scale, d.shape[1:],
-                                                 axis=axis).mean(axis=0)
-                if axes_tree is None:
-                    dbar = jax.tree.map(lambda d: pm(d, -1), delta)
+        with jax.named_scope("sync_mean"):
+            if mode != "none" and ls.wire_pack:
+                # 1-bit wire format. Bucketized: one packed gather per dtype
+                # bucket (make_packed_mean_flat; meshless fallback in CPU
+                # tests). Per-leaf path kept for sharded leaves / equivalence.
+                pm, axes_tree = packed_mean_fn or (None, None)
+                if bucket_sync:
+                    dbar = bucket_packed_mean(delta, bucketable,
+                                              flat_fn=packed_mean_flat_fn,
+                                              leaf_fn=pm, axes_tree=axes_tree)
                 else:
-                    dbar = jax.tree.map(pm, delta, axes_tree)
-        elif bucket_sync:
-            dbar = bucket_worker_mean(delta, bucketable)
-        else:
-            dbar = jax.tree.map(lambda d: d.mean(axis=0), delta)
+                    if pm is None:
+                        def pm(d, axis=-1):
+                            packed, scale = comp.pack_signs(d, axis=axis)
+                            return comp.unpack_signs(
+                                packed, scale, d.shape[1:],
+                                axis=axis).mean(axis=0)
+                    if axes_tree is None:
+                        dbar = jax.tree.map(lambda d: pm(d, -1), delta)
+                    else:
+                        dbar = jax.tree.map(pm, delta, axes_tree)
+            elif bucket_sync:
+                dbar = bucket_worker_mean(delta, bucketable)
+            else:
+                dbar = jax.tree.map(lambda d: d.mean(axis=0), delta)
 
         new_stats = state.stats
         if record:

@@ -13,12 +13,16 @@ is appended to the comms ledger and (optionally) one JSONL line in
 decisions).
 
 With a ``telemetry.trace.Tracer`` (ISSUE 8) the loop is additionally
-span-instrumented — ``round`` / ``local_steps`` / ``sync`` (+ per-stage
-``collective`` attribution) / ``controller`` / ``eval`` / ``checkpoint``
-— feeding the Perfetto/Prometheus exporters, the per-stage ``stage_s``
-seconds in the ledger rows and JSONL, and a run manifest beside the
-JSONL.  Without a tracer the loop runs the exact untraced code path
-(pinned bitwise by tests/test_trace.py).
+span-instrumented — ``fit_setup``, then per step ``round`` /
+``local_steps`` (children ``batch`` and ``step_dispatch``) / ``sync``
+(+ per-stage ``collective`` attribution) / ``ledger`` / ``controller``
+/ ``readback`` / ``eval`` / ``checkpoint`` — feeding the
+Perfetto/Prometheus exporters, the per-stage ``stage_s`` seconds in the
+ledger rows and JSONL, and a run manifest beside the JSONL.  Every
+stretch of host code in the loop sits in a leaf span, so a profiler
+capture's device idle time can be attributed to what the host was doing
+(``trace.clock_offset_ns``).  Without a tracer the loop runs the exact
+untraced code path (pinned bitwise by tests/test_trace.py).
 
 CLI (end-to-end example entry point):
     PYTHONPATH=src python -m repro.launch.train --arch paper-lm --steps 200
@@ -113,15 +117,10 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
     num_steps = num_steps or run.steps
     ls = run.local_sgd
 
-    rng = jax.random.PRNGKey(seed)
-    params0 = mbase.materialize(bundle.specs, rng,
-                                dtype=jnp.dtype(run.model.param_dtype))
-    state = bundle.init(jax.random.fold_in(rng, 1), params0)
+    tracer = tracer if tracer is not None else tele.NULL
+    mreg = tracer.metrics
+    jit0 = tele.jit_events() if tracer.enabled else None
 
-    controller = controller or make_controller(run, n_comp=bundle.n_comp)
-    sched = DynamicSchedule(ls, controller.h_at)
-    ledger = tele.CommsLedger()
-    cost_cache: dict = {}
     # the round plan: built once by build_train (bundle.sync_plan) or —
     # for hand-made bundles — compiled here from the state's own bucket
     # layout with the config's declared topology.  The controller
@@ -136,21 +135,6 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
             compression=ls.sync_compression, num_workers=bundle_.num_workers,
             wire_pack=ls.wire_pack, coalesce=ls.sync_coalesce,
             worker_axes=wa, anchored=needs_anchor(ls))
-
-    plan = bundle.sync_plan
-    if plan is None:
-        plan = config_plan(bundle, state)
-    # align round 1 with the controller's INITIAL decision: the
-    # error-driven compressor policies (auto_compress, noise_adaptive)
-    # start uncompressed and escalate from measured error, so the
-    # config's declared wire format must not leak into the first sync.
-    # Identity policies emit an empty rewrite and the config plan
-    # passes through as the SAME object (static stays bitwise).
-    plan = controller.plan_delta(0).apply(plan)
-    # abstract avals of the state, for lowering sync in the ledger cost
-    # path — holding the concrete init state alive here would pin a
-    # second full optimizer state in device memory for the whole run
-    state_avals = jax.eval_shape(lambda s: s, state)
 
     def measured_cost(p, scope):
         """Ledger pricing: stage rows come from the plan's ring-model
@@ -187,15 +171,42 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
             cost_cache[key] = cost
         return cost_cache[key]
 
-    tracer = tracer if tracer is not None else tele.NULL
-    mreg = tracer.metrics
-    if tracer.enabled and (manifest_path or telemetry_path):
-        # the reproducibility sidecar BESIDE the JSONL: written up front
-        # so a crashed run still identifies itself to the trend tooling
-        from repro.telemetry import export as texport
-        texport.write_run_manifest(
-            manifest_path or f"{telemetry_path}.manifest.json",
-            run=run, plan=plan, layout=bundle.layout, mesh=mesh)
+    with tracer.span("fit_setup") as setup_sp:
+        rng = jax.random.PRNGKey(seed)
+        params0 = mbase.materialize(bundle.specs, rng,
+                                    dtype=jnp.dtype(run.model.param_dtype))
+        state = bundle.init(jax.random.fold_in(rng, 1), params0)
+        if tracer.enabled:
+            setup_sp.set(state_leaves=len(jax.tree.leaves(state)))
+
+        controller = controller or make_controller(run, n_comp=bundle.n_comp)
+        sched = DynamicSchedule(ls, controller.h_at)
+        ledger = tele.CommsLedger()
+        cost_cache: dict = {}
+        plan = bundle.sync_plan
+        if plan is None:
+            plan = config_plan(bundle, state)
+        # align round 1 with the controller's INITIAL decision: the
+        # error-driven compressor policies (auto_compress, noise_adaptive)
+        # start uncompressed and escalate from measured error, so the
+        # config's declared wire format must not leak into the first
+        # sync.  Identity policies emit an empty rewrite and the config
+        # plan passes through as the SAME object (static stays bitwise).
+        plan = controller.plan_delta(0).apply(plan)
+        # abstract avals of the state, for lowering sync in the ledger
+        # cost path — holding the concrete init state alive here would
+        # pin a second full optimizer state in device memory for the
+        # whole run
+        state_avals = jax.eval_shape(lambda s: s, state)
+
+        if tracer.enabled and (manifest_path or telemetry_path):
+            # the reproducibility sidecar BESIDE the JSONL: written up
+            # front so a crashed run still identifies itself to the
+            # trend tooling
+            from repro.telemetry import export as texport
+            texport.write_run_manifest(
+                manifest_path or f"{telemetry_path}.manifest.json",
+                run=run, plan=plan, layout=bundle.layout, mesh=mesh)
 
     tlog = None
     history = []
@@ -228,12 +239,15 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
                 round_span = tracer.start("round", round=global_rounds + 1,
                                           step=t, h=h_now)
             with tracer.span("local_steps", step=t) as stp:
-                batch = _scaled_batch(data_iter, controller.batch_scale())
-                if lr_scale_now == 1.0:
-                    state, metrics = bundle.local_step(state, batch)
-                else:
-                    state, metrics = bundle.local_step(state, batch,
-                                                       lr_scale_now)
+                with tracer.span("batch"):
+                    batch = _scaled_batch(data_iter,
+                                          controller.batch_scale())
+                with tracer.span("step_dispatch"):
+                    if lr_scale_now == 1.0:
+                        state, metrics = bundle.local_step(state, batch)
+                    else:
+                        state, metrics = bundle.local_step(state, batch,
+                                                           lr_scale_now)
                 stp.fence(state)
             if mreg is not None:
                 tmetrics.observe_step(mreg, stp.dur_s)
@@ -244,18 +258,20 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
                                  topology=plan.topology.describe()) as ssp:
                     state = bundle.sync(state, plan=plan, scope="block")
                     ssp.fence(state)
-                stage_s = tele.sync_stage_spans(tracer, plan, "block", ssp)
-                entry = ledger.record_plan(
-                    step=t, level=1, h=h_now, plan=plan, scope="block",
-                    measured=measured_cost(plan, "block"),
-                    seconds=ssp.dur_s, num_workers=bundle.num_workers)
-                comm_rounds["block"] += 1
-                synced = "block"
-                if mreg is not None:
-                    tmetrics.observe_round(
-                        mreg, scope="block", h=h_now,
-                        wire_bytes=entry["bytes_on_wire"],
-                        sync_s=ssp.dur_s, stage_s=stage_s)
+                with tracer.span("ledger"):
+                    stage_s = tele.sync_stage_spans(tracer, plan, "block",
+                                                    ssp)
+                    entry = ledger.record_plan(
+                        step=t, level=1, h=h_now, plan=plan, scope="block",
+                        measured=measured_cost(plan, "block"),
+                        seconds=ssp.dur_s, num_workers=bundle.num_workers)
+                    comm_rounds["block"] += 1
+                    synced = "block"
+                    if mreg is not None:
+                        tmetrics.observe_round(
+                            mreg, scope="block", h=h_now,
+                            wire_bytes=entry["bytes_on_wire"],
+                            sync_s=ssp.dur_s, stage_s=stage_s)
             elif level == 2:
                 # the plan already carries last round's PlanDelta
                 # (compressor modes / topology) — no loose kwargs
@@ -264,49 +280,53 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
                     state = bundle.sync(state, plan=plan, scope="global")
                     ssp.fence(state)
                 sync_s = ssp.dur_s
-                stage_s = tele.sync_stage_spans(tracer, plan, "global", ssp)
-                global_rounds += 1
-                entry = ledger.record_plan(
-                    step=t, level=2, h=h_now, plan=plan, scope="global",
-                    measured=measured_cost(plan, "global"),
-                    batch_scale=controller.batch_scale(),
-                    lr_scale=lr_scale_now, seconds=sync_s,
-                    num_workers=bundle.num_workers)
-                comm_rounds["global"] += 1
-                synced = "global"
-                stats = (tele.round_summary(state.stats)
-                         if bundle.telemetry else {})
-                # backend step-time census: None on lockstep backends
-                # (one vmap, one clock — the gauge stays 0.0); the
-                # simulated/distributed backends report per-ACTIVE-worker
-                # seconds, the straggler sensor for the elastic policy
-                wtimes = backend.worker_step_times(h=h_now,
-                                                  measured_s=stp.dur_s)
-                if wtimes:
-                    ts = [float(x) for x in wtimes]
-                    mean_t = sum(ts) / len(ts)
-                    ws = backend.worker_set
-                    active = ws.active or ws.ids
-                    stats["worker_step_s"] = ts
-                    stats["worker_step_skew"] = (
-                        (max(ts) - min(ts)) / mean_t if mean_t > 0 else 0.0)
-                    stats["worker_slowest"] = int(
-                        active[max(range(len(ts)), key=ts.__getitem__)])
-                    stats.setdefault("num_workers", ws.num_workers)
-                # by-id census covers demoted workers too — the sensor
-                # the promotion-back path needs (a recovered straggler
-                # is invisible in the active-only skew above)
-                wtimes_by_id = backend.worker_times_by_id(
-                    h=h_now, measured_s=stp.dur_s)
-                if wtimes_by_id:
-                    stats["worker_step_s_by_id"] = {
-                        int(k): float(v) for k, v in wtimes_by_id.items()}
-                report = RoundReport(
-                    round=global_rounds, step=t, h=h_now,
-                    loss=float(metrics["loss"]),
-                    stats=stats,
-                    wire_bytes=entry["bytes_on_wire"],
-                    collectives=entry["collectives"])
+                with tracer.span("ledger"):
+                    stage_s = tele.sync_stage_spans(tracer, plan, "global",
+                                                    ssp)
+                    global_rounds += 1
+                    entry = ledger.record_plan(
+                        step=t, level=2, h=h_now, plan=plan, scope="global",
+                        measured=measured_cost(plan, "global"),
+                        batch_scale=controller.batch_scale(),
+                        lr_scale=lr_scale_now, seconds=sync_s,
+                        num_workers=bundle.num_workers)
+                    comm_rounds["global"] += 1
+                    synced = "global"
+                    stats = (tele.round_summary(state.stats)
+                             if bundle.telemetry else {})
+                    # backend step-time census: None on lockstep backends
+                    # (one vmap, one clock — the gauge stays 0.0); the
+                    # simulated/distributed backends report
+                    # per-ACTIVE-worker seconds, the straggler sensor for
+                    # the elastic policy
+                    wtimes = backend.worker_step_times(h=h_now,
+                                                      measured_s=stp.dur_s)
+                    if wtimes:
+                        ts = [float(x) for x in wtimes]
+                        mean_t = sum(ts) / len(ts)
+                        ws = backend.worker_set
+                        active = ws.active or ws.ids
+                        stats["worker_step_s"] = ts
+                        stats["worker_step_skew"] = (
+                            (max(ts) - min(ts)) / mean_t if mean_t > 0
+                            else 0.0)
+                        stats["worker_slowest"] = int(
+                            active[max(range(len(ts)), key=ts.__getitem__)])
+                        stats.setdefault("num_workers", ws.num_workers)
+                    # by-id census covers demoted workers too — the sensor
+                    # the promotion-back path needs (a recovered straggler
+                    # is invisible in the active-only skew above)
+                    wtimes_by_id = backend.worker_times_by_id(
+                        h=h_now, measured_s=stp.dur_s)
+                    if wtimes_by_id:
+                        stats["worker_step_s_by_id"] = {
+                            int(k): float(v) for k, v in wtimes_by_id.items()}
+                    report = RoundReport(
+                        round=global_rounds, step=t, h=h_now,
+                        loss=float(metrics["loss"]),
+                        stats=stats,
+                        wire_bytes=entry["bytes_on_wire"],
+                        collectives=entry["collectives"])
                 delta = traced_decision(tracer, controller, report, t + 1)
                 plan = delta.apply(plan)
                 if getattr(delta, "lr_scale", None) is not None:
@@ -411,7 +431,8 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
                         resizes += 1
                     log(f"resize: W {old_w} -> {new_w} at step {t} "
                         f"(lr x{lr_resize:g})")
-            rec = {k: float(v) for k, v in metrics.items()}
+            with tracer.span("readback", step=t):
+                rec = {k: float(v) for k, v in metrics.items()}
             rec.update(step=t, synced=synced)
             history.append(rec)
             if eval_every and eval_fn and (t + 1) % eval_every == 0:
@@ -443,8 +464,11 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
                               "batch_scale": controller.batch_scale(),
                               "lr_scale": lr_scale_now}}
     if tracer.enabled:
+        jit = {k: n - jit0[k] for k, n in tele.jit_events().items()}
         summary["trace"] = {"spans": len(tracer.spans),
-                            "fenced": tracer.fence}
+                            "fenced": tracer.fence, **jit}
+        if mreg is not None:
+            tmetrics.observe_jit_events(mreg, jit)
     return state, history, summary
 
 

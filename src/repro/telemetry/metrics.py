@@ -30,6 +30,9 @@ Metric names are prefixed ``repro_``.  The standard set ``fit`` emits:
   workers step in lockstep inside one XLA program, so ``fit`` reports a
   structural 0.0; multi-host backends feed real per-worker timings
   through :func:`observe_worker_times` (the elastic-pool sensor).
+* ``repro_jit_events_total``    (counter, label ``kind``: ``traces`` /
+  ``compiles`` / ``cache_loads``) JAX traces, backend compiles and
+  persistent-cache loads during the ``fit`` (``trace.jit_events``)
 """
 from __future__ import annotations
 
@@ -212,6 +215,16 @@ def observe_worker_times(reg: MetricsRegistry, worker_step_s=None):
     ts = [float(t) for t in worker_step_s]
     mean = sum(ts) / len(ts)
     g.set((max(ts) - min(ts)) / mean if mean > 0 else 0.0)
+
+
+def observe_jit_events(reg: MetricsRegistry, counts: dict):
+    """JAX traces / compiles / cache loads, ``{kind: n}`` from
+    ``trace.jit_events`` deltas."""
+    c = reg.counter("jit_events_total",
+                    "JAX traces, backend compiles and persistent-cache "
+                    "loads", labels=("kind",))
+    for kind, n in counts.items():
+        c.labels(kind=kind).inc(n)
 
 
 def observe_round(reg: MetricsRegistry, *, scope: str, h: int,

@@ -14,6 +14,10 @@
   manifest + extended JSONL, with trace stage ids matching the ledger's
   priced stage rows; and tracing (even fenced) is a pure observer —
   the trajectory is BITWISE identical with it on or off.
+* Span tree and driver loop: parent links, self time, a leaf span for
+  every stretch of host code in ``fit``'s loop, JAX trace/compile
+  counts on the span that paid for them, no listener for the
+  NullTracer, and ``clock_offset_ns`` on a real CPU profiler capture.
 """
 import json
 
@@ -26,6 +30,7 @@ from repro.configs.base import (ControllerConfig, InputShape, LocalSGDConfig,
                                 ModelConfig, OptimConfig, RunConfig)
 from repro.core import flatbuf
 from repro.core import syncplan as splan
+from repro.core.controller import StaticController
 from repro.core.local_sgd import make_local_sgd
 from repro.launch.steps import TrainBundle
 from repro.launch.train import fit
@@ -434,3 +439,209 @@ def test_traced_noise_adaptive_controller_spans(tmp_path):
                 "decisions"} <= set(s.attrs)
     # decisions trace the sensor->actuator provenance at least once
     assert any(s.attrs["decisions"] for s in ctl)
+
+
+# ---------------------------------------------------------------------------
+# span tree, compile counters and the shared clock
+# ---------------------------------------------------------------------------
+
+DRIVER_LEAVES = ("fit_setup", "batch", "step_dispatch", "readback", "ledger")
+
+
+def test_spans_link_to_their_parent_and_self_time_subtracts_children():
+    tr = ttrace.Tracer()
+    with tr.span("round") as rd:
+        with tr.span("local_steps") as ls:
+            with tr.span("batch") as b:
+                pass
+            with tr.span("step_dispatch") as d:
+                pass
+        with tr.span("sync") as sy:
+            pass
+    with tr.span("readback") as rb:
+        pass
+    assert rd.parent is None and rb.parent is None
+    assert ls.parent == rd.id and sy.parent == rd.id
+    assert b.parent == ls.id and d.parent == ls.id
+    assert len({s.id for s in tr.spans}) == len(tr.spans)
+    for s in (rd, ls, b, d, sy, rb):        # pin for exact arithmetic
+        s.dur_s = {rd.id: 10.0, ls.id: 6.0, b.id: 1.0, d.id: 4.0,
+                   sy.id: 3.0, rb.id: 2.0}[s.id]
+    rd.ts_s, ls.ts_s, b.ts_s, d.ts_s, sy.ts_s = 0.0, 0.0, 0.5, 1.5, 6.5
+    own = ttrace.self_time_s(tr.spans)
+    assert own[ls.id] == pytest.approx(1.0)      # 6 - (1 + 4)
+    assert own[rd.id] == pytest.approx(1.0)      # 10 - (6 + 3)
+    assert own[b.id] == pytest.approx(1.0) and own[rb.id] == 2.0
+
+
+def test_finish_out_of_order_leaves_the_open_stack_consistent():
+    tr = ttrace.Tracer()
+    rd = tr.start("round")
+    ls = tr.start("local_steps")
+    tr.finish(rd, incomplete=True)       # the mid-round exit path
+    with tr.span("batch") as b:
+        pass
+    assert b.parent == ls.id
+    tr.finish(ls)
+    with tr.span("readback") as rb:
+        pass
+    assert rb.parent is None
+    rec = tr.record("collective", 0.0, 1.0, parent=ls.id, stage=0)
+    assert rec.parent == ls.id and rec.attrs == {"stage": 0}
+
+
+def _jit_quad_bundle(run):
+    """The quad bundle with its local step and sync jitted, as
+    ``build_train`` jits them: a fresh bundle traces on first use."""
+    init, local_step, sync = make_local_sgd(run, quad_loss, num_workers=W,
+                                            use_kernel=False)
+    jsync = jax.jit(lambda s, plan, scope: sync(s, plan=plan, scope=scope),
+                    static_argnums=(1, 2))
+    return TrainBundle(cfg=run.model, run=run, layout=None, num_workers=W,
+                       specs=QUAD_SPECS, init=init,
+                       local_step=jax.jit(local_step),
+                       sync=lambda s, *, plan=None, scope=None:
+                           jsync(s, plan, scope),
+                       telemetry=False, n_comp=1)
+
+
+def test_traced_fit_puts_every_driver_stretch_in_a_leaf_span():
+    steps, H = 6, 2
+    run = _quad_run(H=H, steps=steps, sync_compression="none")
+    tr = ttrace.Tracer()
+    fit(run, quad_batches(), bundle=_jit_quad_bundle(run), num_steps=steps,
+        tracer=tr, log=lambda *a, **k: None)
+    by_id = {s.id: s for s in tr.spans}
+    count = {n: sum(s.name == n for s in tr.spans) for n in DRIVER_LEAVES}
+    assert count == {"fit_setup": 1, "batch": steps, "step_dispatch": steps,
+                     "readback": steps, "ledger": steps // H}
+    setup = next(s for s in tr.spans if s.name == "fit_setup")
+    assert setup.parent is None
+    # params, momentum, step and rng of the quad model's state
+    assert setup.attrs["state_leaves"] == 2 + 2 + 1 + 1
+    eps = 1e-6
+    for s in tr.spans:
+        if s.name in ("batch", "step_dispatch"):
+            assert by_id[s.parent].name == "local_steps"
+        if s.name == "local_steps":
+            rd = by_id[s.parent]
+            assert rd.name == "round"
+            kids = [k for k in tr.spans if k.parent == s.id]
+            assert {k.name for k in kids} == {"batch", "step_dispatch"}
+            assert sum(k.dur_s for k in kids) <= s.dur_s + eps
+            assert s.dur_s <= rd.dur_s + eps
+        if s.name in ("readback", "ledger"):
+            assert s.parent is None or by_id[s.parent].name == "round"
+    assert ttrace.self_time_s(tr.spans)[setup.id] == pytest.approx(
+        setup.dur_s)
+
+
+class _DoubleBatchAfterRoundOne(StaticController):
+    """Static H; the batch doubles once the first round is reported."""
+
+    def __init__(self, run):
+        super().__init__(run)
+        self.scale = 1
+
+    def batch_scale(self) -> int:
+        return self.scale
+
+    def update(self, report):
+        self.scale = 2
+
+
+def test_a_retrace_shows_on_the_step_dispatch_that_paid_for_it():
+    """A fresh bundle traces its step on the first call; a new batch
+    scale traces it again; steady steps trace nothing."""
+    steps, H = 6, 2
+    run = _quad_run(H=H, steps=steps, sync_compression="none")
+    tr = ttrace.Tracer()
+    _, _, summary = fit(run, quad_batches(), bundle=_jit_quad_bundle(run),
+                        num_steps=steps, tracer=tr,
+                        controller=_DoubleBatchAfterRoundOne(run),
+                        log=lambda *a, **k: None)
+    by_id = {s.id: s for s in tr.spans}
+    traces = {by_id[s.parent].attrs["step"]: s.attrs.get("traces", 0)
+              for s in tr.spans if s.name == "step_dispatch"}
+    assert traces[0] >= 1 and traces[H] >= 1
+    assert all(traces[t] == 0 for t in range(steps) if t not in (0, H))
+    assert summary["trace"]["traces"] >= traces[0] + traces[H]
+    assert summary["trace"]["compiles"] >= 2
+    assert {"traces", "compiles", "cache_loads"} <= set(summary["trace"])
+
+
+def test_jit_event_totals_reach_the_prometheus_exposition():
+    run = _quad_run(H=2, steps=2, sync_compression="none")
+    tr = ttrace.Tracer(metrics=tmetrics.MetricsRegistry())
+    _, _, summary = fit(run, quad_batches(), bundle=_jit_quad_bundle(run),
+                        num_steps=2, tracer=tr, log=lambda *a, **k: None)
+    text = tr.metrics.exposition()
+    for kind in ("traces", "compiles", "cache_loads"):
+        n = summary["trace"][kind]
+        assert f'repro_jit_events_total{{kind="{kind}"}} {n}' in text
+
+
+def test_null_tracer_records_nothing_and_registers_no_listener(monkeypatch):
+    import jax.monitoring
+    calls = []
+    monkeypatch.setattr(ttrace, "_listening", False)
+    monkeypatch.setattr(jax.monitoring, "register_event_listener",
+                        calls.append)
+    monkeypatch.setattr(jax.monitoring,
+                        "register_event_duration_secs_listener", calls.append)
+    run = _quad_run(H=2, steps=2, sync_compression="none")
+    fit(run, quad_batches(), bundle=_jit_quad_bundle(run), num_steps=2,
+        log=lambda *a, **k: None)
+    ttrace.NullTracer()
+    assert calls == [] and ttrace.NULL.spans == []
+    ttrace.Tracer()
+    ttrace.Tracer()
+    assert len(calls) == 2                  # once per process
+
+
+def _profiled_spans(trace_dir, names):
+    import glob
+    import os
+
+    from jax.profiler import ProfileData
+    path = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
+                     recursive=True)[0]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name in names:
+                        s = int(ev.start_ns)
+                        out.append((s, s + int(ev.duration_ns), ev.name))
+    return out
+
+
+def test_clock_offset_lays_tracer_spans_on_a_profile_capture(tmp_path):
+    import time
+    names = ("round", "local_steps", "sync")
+    tr = ttrace.Tracer(annotate=True)
+    x = jnp.full((64, 64), 0.01)
+    with jax.profiler.trace(str(tmp_path)):
+        for _ in range(4):
+            with tr.span("round"):
+                with tr.span("local_steps"):
+                    x = jnp.tanh(x @ x).block_until_ready()
+                time.sleep(0.002)
+                with tr.span("sync"):
+                    x = (x * 0.5).block_until_ready()
+    profiled = _profiled_spans(str(tmp_path), names)
+    off = ttrace.clock_offset_ns(tr.spans, profiled)
+    assert off is not None
+    for name in names:
+        mine = sorted((s.ts_s, s.dur_s) for s in tr.spans if s.name == name)
+        theirs = sorted((s, e) for s, e, n in profiled if n == name)
+        assert len(mine) == len(theirs) == 4
+        for (ts, dur), (s, e) in zip(mine, theirs):
+            assert abs(s - (ts * 1e9 + off)) < 0.5e6
+            assert abs(e - ((ts + dur) * 1e9 + off)) < 0.5e6
+    # names whose counts disagree are not paired; nothing left -> None
+    rounds = [p for p in profiled if p[2] == "round"]
+    assert ttrace.clock_offset_ns(tr.spans, rounds[:-1]) is None
+    assert ttrace.clock_offset_ns(tr.spans, []) is None
+    assert ttrace.clock_offset_ns([], profiled) is None
