@@ -247,28 +247,50 @@ def build_train(run: RunConfig, *, mesh: Mesh | None = None,
         # positional adapter for the optional lr_scale arg (pjit with
         # in_shardings rejects kwargs): passing None keeps the original
         # two-arg program; a scalar traces once and serves every value
-        jstep = jax.jit(
-            lambda s, b, lr_scale: local_step(s, b, lr_scale=lr_scale),
-            **step_kw)
-        bundle.local_step = (lambda s, b, lr_scale=None:
-                             jstep(s, b, lr_scale))
-        bundle.step_lower = (lambda s, b, lr_scale=None:
-                             jstep.lower(s, b, lr_scale))
+        def step_fn(s, b, lr_scale):
+            return local_step(s, b, lr_scale=lr_scale)
+
+        def step_api(jitted):
+            def call(s, b, lr_scale=None):
+                return jitted(s, b, lr_scale)
+            call.lower = lambda s, b, lr_scale=None: jitted.lower(s, b,
+                                                                  lr_scale)
+            return call
+
         # jit a positional adapter for the static (group, compression,
         # plan, scope) args — SyncPlan is frozen/hashable, so each
         # distinct plan compiles once — and keep the kwarg interface
-        # fit expects; the raw jitted object rides along so fit can
-        # .lower() the sync for the HLO-measured ledger costs.
-        jsync = jax.jit(
-            lambda s, group, compression, plan, scope: sync(
-                s, group=group, compression=compression, plan=plan,
-                scope=scope),
-            static_argnums=(1, 2, 3, 4), **sync_kw)
-        bundle.sync = (lambda s, *, group=None, compression=None, plan=None,
-                       scope=None: jsync(s, group, compression, plan, scope))
-        bundle.sync_lower = (lambda s, *, group=None, compression=None,
-                             plan=None, scope=None:
-                             jsync.lower(s, group, compression, plan, scope))
+        # fit expects; ``.lower`` rides along so fit can price the sync
+        # from its compiled HLO (the ledger costs).
+        def sync_fn(s, group, compression, plan, scope):
+            return sync(s, group=group, compression=compression, plan=plan,
+                        scope=scope)
+
+        def sync_api(jitted):
+            def call(s, *, group=None, compression=None, plan=None,
+                     scope=None):
+                return jitted(s, group, compression, plan, scope)
+            call.lower = (lambda s, *, group=None, compression=None,
+                          plan=None, scope=None:
+                          jitted.lower(s, group, compression, plan, scope))
+            return call
+
+        sync_kw = dict(static_argnums=(1, 2, 3, 4), **sync_kw)
+        bundle.local_step = step_api(jax.jit(step_fn, **step_kw))
+        bundle.sync = sync_api(jax.jit(sync_fn, **sync_kw))
+        # fit's donating twins: the same programs with the state donated,
+        # so each output state takes its input's buffers (a pass-through
+        # leaf is aliased, not copied) and the next program can be queued
+        # while one runs without room for a second whole state.  A twin
+        # rides on the public callable as ``.donating``: a caller that
+        # replaces ``bundle.local_step`` / ``bundle.sync`` runs its
+        # replacement, and the public callables leave their input valid.
+        bundle.local_step.donating = step_api(
+            jax.jit(step_fn, donate_argnums=0, **step_kw))
+        bundle.sync.donating = sync_api(
+            jax.jit(sync_fn, donate_argnums=0, **sync_kw))
+        bundle.step_lower = bundle.local_step.lower
+        bundle.sync_lower = bundle.sync.lower
     return bundle
 
 

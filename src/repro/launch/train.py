@@ -71,6 +71,21 @@ def _scaled_batch(data_iter, scale: int):
     return jax.tree.map(lambda *xs: jnp.concatenate(xs, axis=1), *parts)
 
 
+def _donating(fn):
+    """``fn``'s donating twin where ``build_train`` made one, else ``fn``:
+    hand-made bundles and replaced callables run as they are."""
+    return getattr(fn, "donating", fn)
+
+
+def _queued(out) -> int:
+    """1 where ``out``, an output of the program dispatched last, is not
+    ready yet (``is_ready`` does not block): a dispatch that begins now
+    is queued behind a program still running."""
+    leaves = jax.tree.leaves(out)
+    ready = getattr(leaves[0], "is_ready", None) if leaves else None
+    return int(ready is not None and not ready())
+
+
 def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
         eval_every=0, eval_fn=None, log=print, mesh=None, layout=None,
         controller=None, telemetry_path=None, tracer=None,
@@ -86,6 +101,21 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
     ``stage_s`` and write a run manifest at ``manifest_path`` (default:
     ``<telemetry_path>.manifest.json``).  ``checkpoint_fn(state, step)``
     runs every ``checkpoint_every`` steps inside a ``checkpoint`` span.
+
+    ``fit`` makes the state itself and donates it into every program:
+    where the bundle's ``local_step`` / ``sync`` carry a ``.donating``
+    twin (``build_train`` makes them), each program reuses its input
+    state's buffers for its output, so the next program can be queued
+    on the device while one runs.  A state handed to ``eval_fn`` or
+    ``checkpoint_fn`` is valid only during that call: the next step
+    consumes its buffers, so a hook that keeps it (or a leaf of it) for
+    later, an asynchronous save among them, must copy it first.  Step
+    t's metrics are read into ``history`` after step t+1 (and any sync
+    of step t) has been dispatched, the last step's after the loop; an
+    ``eval_every`` step reads its own at once.  The history, the
+    round's ``RoundReport`` (whose loss the ``ledger`` span reads at the
+    round's end) and the controller's inputs are what an immediate read
+    gives.
 
     ``backend`` (ISSUE 9) is the execution substrate owning the
     WorkerSet (repro.backend).  None builds the default ``LocalBackend``
@@ -227,6 +257,17 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
     # one "round" span per global round: opened at the round's first
     # local step, closed when its global sync (+ decision) completes
     round_span = None
+    # the step whose metrics are not read yet, and an output of the
+    # program dispatched last (``queued`` on the dispatch spans)
+    pending = None
+    last_out = None
+
+    def read_back(metrics, t, synced):
+        rec = {k: float(v) for k, v in metrics.items()}
+        rec.update(step=t, synced=synced)
+        history.append(rec)
+        return rec
+
     t_start = time.perf_counter()
     try:
         # opened inside the try so a raise anywhere in the loop (or in
@@ -242,13 +283,16 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
                 with tracer.span("batch"):
                     batch = _scaled_batch(data_iter,
                                           controller.batch_scale())
-                with tracer.span("step_dispatch"):
+                with tracer.span("step_dispatch") as dsp:
+                    if tracer.enabled:
+                        dsp.set(queued=_queued(last_out))
+                    step_fn = _donating(bundle.local_step)
                     if lr_scale_now == 1.0:
-                        state, metrics = bundle.local_step(state, batch)
+                        state, metrics = step_fn(state, batch)
                     else:
-                        state, metrics = bundle.local_step(state, batch,
-                                                           lr_scale_now)
+                        state, metrics = step_fn(state, batch, lr_scale_now)
                 stp.fence(state)
+            last_out = metrics
             if mreg is not None:
                 tmetrics.observe_step(mreg, stp.dur_s)
             level = sched.advance(t)
@@ -256,8 +300,12 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
             if level == 1:
                 with tracer.span("sync", scope="block",
                                  topology=plan.topology.describe()) as ssp:
-                    state = bundle.sync(state, plan=plan, scope="block")
+                    if tracer.enabled:
+                        ssp.set(queued=_queued(last_out))
+                    state = _donating(bundle.sync)(state, plan=plan,
+                                                   scope="block")
                     ssp.fence(state)
+                last_out = state.params
                 with tracer.span("ledger"):
                     stage_s = tele.sync_stage_spans(tracer, plan, "block",
                                                     ssp)
@@ -277,8 +325,12 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
                 # (compressor modes / topology) — no loose kwargs
                 with tracer.span("sync", scope="global",
                                  topology=plan.topology.describe()) as ssp:
-                    state = bundle.sync(state, plan=plan, scope="global")
+                    if tracer.enabled:
+                        ssp.set(queued=_queued(last_out))
+                    state = _donating(bundle.sync)(state, plan=plan,
+                                                   scope="global")
                     ssp.fence(state)
+                last_out = state.params
                 sync_s = ssp.dur_s
                 with tracer.span("ledger"):
                     stage_s = tele.sync_stage_spans(tracer, plan, "global",
@@ -431,11 +483,17 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
                         resizes += 1
                     log(f"resize: W {old_w} -> {new_w} at step {t} "
                         f"(lr x{lr_resize:g})")
-            with tracer.span("readback", step=t):
-                rec = {k: float(v) for k, v in metrics.items()}
-            rec.update(step=t, synced=synced)
-            history.append(rec)
+            # step t-1's record is read only now, so the host waits on
+            # it with step t (and its sync) already queued behind it; an
+            # eval step reads its own record at once
+            if pending is not None:
+                with tracer.span("readback", step=pending[1]):
+                    read_back(*pending)
+            pending = (metrics, t, synced)
             if eval_every and eval_fn and (t + 1) % eval_every == 0:
+                with tracer.span("readback", step=t):
+                    rec = read_back(*pending)
+                pending = None
                 with tracer.span("eval", step=t):
                     ev = eval_fn(state)
                 rec.update({f"eval_{k}": float(v) for k, v in ev.items()})
@@ -446,6 +504,9 @@ def fit(run: RunConfig, data_iter, *, bundle=None, num_steps=None, seed=0,
                     and (t + 1) % checkpoint_every == 0:
                 with tracer.span("checkpoint", step=t) as csp:
                     csp.fence(checkpoint_fn(state, t))
+        if pending is not None:
+            with tracer.span("readback", step=pending[1]):
+                read_back(*pending)
     finally:
         if round_span is not None:          # training ended mid-round
             tracer.finish(round_span, incomplete=True)

@@ -20,7 +20,9 @@ the trend tooling key off these):
 ``batch``          drawing (and, with a batch scale, stacking) the next
                    batch of every worker
 ``step_dispatch``  the ``bundle.local_step`` call itself: argument
-                   handling and the launch of the jitted step
+                   handling and the launch of the jitted step (attr
+                   ``queued``: 1 where the program dispatched before it
+                   was still not ready when it began; also on ``sync``)
 ``sync``           one ``bundle.sync`` call (scope attr: ``block``/``global``)
 ``collective``     one collective stage of the SyncPlan schedule — carries
                    the SAME ``stage`` id ``CommsLedger.record_plan`` prices,
@@ -31,8 +33,9 @@ the trend tooling key off these):
                    device)
 ``controller``     one ``update`` + ``plan_delta`` decision, attrs = the
                    emitted PlanDelta + the policy's ``decisions`` provenance
-``readback``       the step's metrics read back to the host (``float``):
-                   where the host waits for the device to finish the step
+``readback``       a step's metrics read back to the host (``float``),
+                   in ``fit`` one step late (attr ``step``: whose): where
+                   the host waits for the device to finish that step
 ``fit_setup``      ``fit`` before its loop: weights made, state initialised,
                    plan and controller set up (attr ``state_leaves``)
 ``resize``         one elastic worker-set resize (state surgery + rebuild)
